@@ -82,6 +82,8 @@ def print_serving(doc: dict) -> None:
 
 
 def main(argv=None) -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="serving tables only (fast; the CI artifact step)")

@@ -5,19 +5,9 @@ import jax
 
 
 def shard_map(body, mesh, in_specs, out_specs, *, axis_names):
-    """jax.shard_map with a fallback for the pre-0.6 experimental API
-    (manual axes are the complement of ``auto`` there; replication checking
-    is ``check_rep`` instead of ``check_vma``).
-
-    Fallback caveats (pre-0.6): the region runs with every mesh axis manual
-    and unchecked replication, and its transpose mis-tracks *scalar*
-    residuals/outputs — keep values crossing the region boundary rank >= 1
-    (see distributed/pipeline_parallel.py).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking off: the one wrapper every
+    manual-axis region in the repo goes through, so a later API change is
+    absorbed here."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=axis_names,
+                         check_vma=False)

@@ -12,6 +12,7 @@ code (the pipelined mode's one-section-per-layer).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -67,6 +68,16 @@ def unit_key(graph: Graph, unit: Unit) -> str:
 
 
 def init_params(plan: ExecutionPlan, rng) -> Dict[str, Any]:
+    """Random parameters for ``plan``.  On a mesh plan every leaf is built
+    directly on its shards (one jitted init with the plan's parameter
+    shardings as outputs), so no device ever holds the whole model."""
+    if plan.rules is None:
+        return _init_params(plan, rng)
+    return jax.jit(functools.partial(_init_params, plan),
+                   out_shardings=plan.rules.params_shardings(plan))(rng)
+
+
+def _init_params(plan: ExecutionPlan, rng) -> Dict[str, Any]:
     graph, dtype = plan.graph, plan.prec.param_dtype
     params: Dict[str, Any] = {}
     for unit in plan.units:

@@ -18,7 +18,12 @@ class KernelSelectPass(Pass):
 
     def run(self, ctx: PlanContext) -> None:
         from repro.kernels.registry import REGISTRY
-        table = REGISTRY.resolve_all(ctx.flow.kernel_backend)
+        backend = ctx.flow.kernel_backend
+        if backend == "auto" and ctx.shape.kind == "train":
+            # the Pallas kernels define no VJP, so "auto" gives a training
+            # cell the reference path (an explicit pin is kept as given)
+            backend = "reference"
+        table = REGISTRY.resolve_all(backend)
         ctx.artifacts["kernels"] = table
         accel = sorted(op for op, b in table.items() if b != "ref")
         ctx.stats[self.name] = {

@@ -83,7 +83,11 @@ class MeshSpec:
 
     # -- device binding -----------------------------------------------------
     def build(self):
-        """Bind to the local devices: ``jax.make_mesh(sizes, names)``.
-        Requires ``self.size`` visible devices."""
+        """Bind to the local devices: ``jax.make_mesh(sizes, names)`` with
+        ``Auto`` axes (the flow places arrays through sharding constraints,
+        which ``Explicit`` axes — ``make_mesh``'s default in current JAX —
+        refuse).  Requires ``self.size`` visible devices."""
         import jax
-        return jax.make_mesh(self.sizes, self.names)
+        return jax.make_mesh(
+            self.sizes, self.names,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(self.axes))

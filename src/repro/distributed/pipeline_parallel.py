@@ -138,8 +138,8 @@ def make_pipeline_loss(plan: ExecutionPlan, mesh, n_microbatches: int,
                              lambda: embed(eparams, toks).astype(dt),
                              lambda: h_in)
             h_out = run_stage_layers(gparams, x)
-            # the accumulator stays rank-1: scalar residuals of this scan
-            # trip a shape-bookkeeping bug in the pre-0.6 shard_map transpose
+            # the accumulator is rank-1 so the per-stage partial loss can
+            # leave the region sharded over pp_axis (out_specs P(pp_axis))
             lmb = jax.lax.cond(
                 jnp.logical_and(ax == n_stages - 1,
                                 jnp.logical_and(mb >= 0, mb < nmb)),
@@ -151,9 +151,8 @@ def make_pipeline_loss(plan: ExecutionPlan, mesh, n_microbatches: int,
         (_, loss), _ = jax.lax.scan(step, (h0, jnp.zeros((1,), jnp.float32)),
                                     jnp.arange(T, dtype=jnp.int32))
         # per-stage partial loss (non-zero on the last stage only), returned
-        # sharded over pp_axis and summed outside the manual region — a
-        # replicated scalar output would need an in-region psum whose
-        # transpose the pre-0.6 shard_map rejects under check_rep=False
+        # sharded over pp_axis and summed outside the manual region, so the
+        # region needs no psum
         return loss / nmb
 
     # shard_map wiring: stacked layer params split over pod; rest replicated

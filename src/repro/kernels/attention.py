@@ -11,7 +11,7 @@ masking skips blocks above the diagonal (the analogue of not generating
 hardware for loop iterations that are statically dead).
 
 Masking is positional: per-row position arrays for queries and keys ride
-into the kernel as (1, bq) / (1, bk) VMEM rows, with padded entries carrying
+into the kernel as a (bq, 1) column / (1, bk) row, with padded entries carrying
 -1 (masked as keys, garbage-and-discarded as queries).  Callers that pass no
 ``positions`` get broadcast aranges — bit-identical to index-space masking —
 while the serving engine's left-padded bucketed prefill passes per-row
@@ -66,8 +66,8 @@ def _kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, m_ref, l_ref,
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
-        qpos = qp_ref[0][:, None]                      # (bq, 1)
-        kpos = kp_ref[0][None, :]                      # (1, bk)
+        qpos = qp_ref[0]                               # (bq, 1)
+        kpos = kp_ref[0]                               # (1, bk)
         valid = kpos >= 0                              # pad keys masked
         if causal:
             valid &= kpos <= qpos
@@ -138,8 +138,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vt = jnp.pad(v, ((0, 0), (0, Skp - Skv), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
     # pad positions with -1: the padded tail is masked positionally (the
     # pre-positional kernel's kv_len test, folded into the arrays)
-    qpp = jnp.pad(qp, ((0, 0), (0, Sqp - Sq)), constant_values=-1)
-    kpp = jnp.pad(kp, ((0, 0), (0, Skp - Skv)), constant_values=-1)
+    # query positions ride as a (bq, 1) column and key positions as a
+    # (1, bk) row: the unit dims satisfy the TPU block-shape rule (last two
+    # block dims divisible by (8, 128) or equal to the array's)
+    qpp = jnp.pad(qp, ((0, 0), (0, Sqp - Sq)),
+                  constant_values=-1)[:, :, None]
+    kpp = jnp.pad(kp, ((0, 0), (0, Skp - Skv)),
+                  constant_values=-1)[:, None, :]
     nq, nk = Sqp // bq, Skp // bk
     grid = (B, H, nq, nk)
 
@@ -154,8 +159,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          lambda b, h, i, kb, G=G: (b, h // G, kb, 0)),
             pl.BlockSpec((1, 1, bk, D),
                          lambda b, h, i, kb, G=G: (b, h // G, kb, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, i, kb: (b, i)),
-            pl.BlockSpec((1, bk), lambda b, h, i, kb: (b, kb)),
+            pl.BlockSpec((1, bq, 1), lambda b, h, i, kb: (b, i, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, i, kb: (b, 0, kb)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, kb: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sqp, D), q.dtype),

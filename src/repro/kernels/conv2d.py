@@ -1,8 +1,9 @@
 """Direct 2-D convolution Pallas kernel with fused BN/activation epilogue.
 
 The paper's workhorse op.  Grid: (batch, C_out tiles, H_out row blocks).
-Each step keeps the full (padded) input feature map of one image in VMEM —
-CNN maps at these sizes are far below the VMEM budget — and contracts the
+Each step keeps the full (padded, space-to-depth) input feature map of one
+image in VMEM — the scoped limit is raised to the working set where a
+lane-sparse map outgrows the default — and contracts the
 kh×kw taps for one block of ``block_h`` output rows as shifted
 (block_h·W_out, C_in)×(C_in, bc) matmuls on the MXU (the TPU-native analogue
 of unrolling the filter loops: taps become statically unrolled matmuls, not
@@ -21,6 +22,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_VMEM_DEFAULT = 16 * 2 ** 20        # Mosaic's default scoped VMEM limit
 
 
 def _kernel(x_ref, w_ref, *rest, kh: int, kw: int, stride: int,
@@ -29,23 +33,24 @@ def _kernel(x_ref, w_ref, *rest, kh: int, kw: int, stride: int,
     if has_bn:
         scale_ref, bias_ref, mean_ref, var_ref = rest[:4]
     o_ref = rest[-1]
-    r0 = pl.program_id(2) * bh * stride         # first input row of the block
-    x = x_ref[0].astype(jnp.float32)            # (Hp, Wp, CI)
-    w = w_ref[...].astype(jnp.float32)          # (kh, kw, CI, bc)
-    ci = x.shape[-1]
-    bc = w.shape[-1]
+    r0 = pl.program_id(2) * bh                  # first output row of the block
+    ci = x_ref.shape[-1]
+    bc = w_ref.shape[-1]
     acc = jnp.zeros((bh * wo, bc), jnp.float32)
     for dh in range(kh):
         for dw in range(kw):
-            sub = jax.lax.dynamic_slice(
-                x, (r0 + dh, dw, 0),
-                ((bh - 1) * stride + 1, (wo - 1) * stride + 1, ci))
-            xs = sub[::stride, ::stride, :].reshape(bh * wo, ci)
-            acc += jnp.dot(xs, w[dh, dw], preferred_element_type=jnp.float32)
+            # tap (dh, dw) of a stride-s conv reads phase (dh % s, dw % s) of
+            # the space-to-depth input at offset (dh // s, dw // s): every
+            # load is a contiguous ref slice, no strided or value slicing
+            ph = (dh % stride) * stride + dw % stride
+            xs = x_ref[0, ph, pl.ds(r0 + dh // stride, bh),
+                       pl.ds(dw // stride, wo), :]
+            xs = xs.astype(jnp.float32).reshape(bh * wo, ci)
+            acc += jnp.dot(xs, w_ref[dh, dw].astype(jnp.float32),
+                           preferred_element_type=jnp.float32)
     if has_bn:
-        inv = jax.lax.rsqrt(var_ref[...].astype(jnp.float32) + 1e-5)
-        acc = ((acc - mean_ref[...]) * (inv * scale_ref[...])
-               + bias_ref[...])
+        inv = jax.lax.rsqrt(var_ref[...] + 1e-5)
+        acc = (acc - mean_ref[...]) * (inv * scale_ref[...]) + bias_ref[...]
     if act:
         acc = _act(acc, act)
     o_ref[0] = acc.reshape(bh, wo, bc).astype(o_ref.dtype)
@@ -78,22 +83,58 @@ def conv2d_fused(x: jax.Array, w: jax.Array, *, stride: int = 1,
     else:
         ho = (H - kh) // stride + 1
         wo = (W - kw) // stride + 1
-    # row blocks index the input via dynamic_slice; both paddings guarantee
-    # x.shape[1] >= (ho-1)*stride + kh, so every block's extent is in range
+    # output columns pad up to the sublane tile (8) so the in-kernel
+    # (bh, wo, CI) -> (bh*wo, CI) merge is tile-aligned; the extra columns
+    # read zero padding and are sliced off below
+    wop = -(-wo // 8) * 8
+    xph = _space_to_depth(x, stride, ho + (kh - 1) // stride,
+                          wop + (kw - 1) // stride)
     bc = _fit_block(CO, min(block_c, CO))
     bh = _fit_block(ho, block_h)
     grid = (N, CO // bc, ho // bh)
-    in_specs = [pl.BlockSpec((1,) + x.shape[1:], lambda n, j, i: (n, 0, 0, 0)),
+    in_specs = [pl.BlockSpec((1,) + xph.shape[1:],
+                             lambda n, j, i: (n, 0, 0, 0, 0)),
                 pl.BlockSpec((kh, kw, CI, bc), lambda n, j, i: (0, 0, 0, j))]
-    operands = [x, w]
+    operands = [xph, w]
     if bn is not None:
         for t in bn:
-            in_specs.append(pl.BlockSpec((bc,), lambda n, j, i: (j,)))
-            operands.append(t.astype(jnp.float32))
+            in_specs.append(pl.BlockSpec((1, bc), lambda n, j, i: (0, j)))
+            operands.append(t.astype(jnp.float32).reshape(1, CO))
     kern = functools.partial(_kernel, kh=kh, kw=kw, stride=stride, bh=bh,
-                             wo=wo, act=act, has_bn=bn is not None)
-    return pl.pallas_call(
+                             wo=wop, act=act, has_bn=bn is not None)
+    # the whole space-to-depth image of one batch element sits in VMEM; a
+    # lane-sparse input (the 3-channel stem) pads C to 128 lanes and can
+    # outgrow the 16 MiB default scoped limit, so the limit follows the
+    # double-buffered working set (v5e has 128 MiB of VMEM)
+    isz = jnp.dtype(x.dtype).itemsize
+    ws = 2 * (_tiled_bytes(xph.shape[1:], isz)
+              + _tiled_bytes((kh, kw, CI, bc), isz)
+              + _tiled_bytes((bh, wop, bc), isz)) + bh * wop * bc * 4
+    y = pl.pallas_call(
         kern, grid=grid, in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bh, wo, bc), lambda n, j, i: (n, i, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((N, ho, wo, CO), x.dtype),
+        out_specs=pl.BlockSpec((1, bh, wop, bc), lambda n, j, i: (n, i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((N, ho, wop, CO), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(_VMEM_DEFAULT, ws + ws // 4)),
         interpret=interpret)(*operands)
+    return y[:, :, :wo] if wop != wo else y
+
+
+def _tiled_bytes(shape, itemsize: int) -> int:
+    """Bytes of a VMEM buffer once its minor dims pad to the (8, 128) tile."""
+    *lead, r, c = shape
+    n = 1
+    for d in lead:
+        n *= d
+    return n * (-(-r // 8) * 8) * (-(-c // 128) * 128) * itemsize
+
+
+def _space_to_depth(x: jax.Array, s: int, hq: int, wq: int) -> jax.Array:
+    """(N, H, W, C) -> (N, s*s, hq, wq, C) with
+    ``out[n, p*s + q, i, j] = x[n, i*s + p, j*s + q]`` (zero past the edge):
+    a stride-s conv becomes s*s stride-1 convs over contiguous phases."""
+    N, H, W, C = x.shape
+    x = jnp.pad(x, ((0, 0), (0, max(hq * s - H, 0)),
+                    (0, max(wq * s - W, 0)), (0, 0)))[:, :hq * s, :wq * s]
+    x = x.reshape(N, hq, s, wq, s, C).transpose(0, 2, 4, 1, 3, 5)
+    return x.reshape(N, s * s, hq, wq, C)

@@ -37,12 +37,12 @@ def _kernel(q_ref, k_ref, v_ref, pos_ref, qpos_ref, o_ref,
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # (G, bk)
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
-    kpos = pos_ref[0]                                    # (bk,)
-    qpos = qpos_ref[0, 0]
+    kpos = pos_ref[0]                                    # (1, bk)
+    qpos = qpos_ref[0]                                   # (1, 1)
     valid = (kpos >= 0) & (kpos <= qpos)
     if window:
         valid &= kpos > qpos - window
-    s = jnp.where(valid[None, :], s, NEG)
+    s = jnp.where(valid, s, NEG)
     m_prev = m_ref[...]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_cur)
@@ -73,7 +73,10 @@ def decode_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     Cp = _rup(C, bk)
     kt = jnp.pad(kc, ((0, 0), (0, Cp - C), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
     vt = jnp.pad(vc, ((0, 0), (0, Cp - C), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
-    pp = jnp.pad(pos, ((0, 0), (0, Cp - C)), constant_values=-1)
+    # positions carry a unit second-minor dim so their blocks meet the TPU
+    # block-shape rule (last two dims divisible by (8, 128) or full)
+    pp = jnp.pad(pos, ((0, 0), (0, Cp - C)), constant_values=-1)[:, None, :]
+    qp = qpos.reshape(B, 1, 1).astype(jnp.int32)
     qt = q.reshape(B, KV, G, D)                          # group per kv head
     nk = Cp // bk
     grid = (B, KV, nk)
@@ -86,15 +89,15 @@ def decode_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
             pl.BlockSpec((1, 1, G, D), lambda b, h, kb: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, kb: (b, h, kb, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, kb: (b, h, kb, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, kb: (b, kb)),
-            pl.BlockSpec((1, 1), lambda b, h, kb: (b, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, kb: (b, 0, kb)),
+            pl.BlockSpec((1, 1, 1), lambda b, h, kb: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, kb: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((G, 1), jnp.float32),
                         pltpu.VMEM((G, 1), jnp.float32),
                         pltpu.VMEM((G, D), jnp.float32)],
-        interpret=interpret)(qt, kt, vt, pp, qpos)
+        interpret=interpret)(qt, kt, vt, pp, qp)
     return out.reshape(B, 1, H, D)
 
 
@@ -108,10 +111,10 @@ def _rup(n, m):
 # ---------------------------------------------------------------------------
 
 def _paged_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, qp_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, bs: int, nblk: int,
-                  window: Optional[int], softcap: Optional[float],
+                  m_ref, l_ref, acc_ref, *, bs: int, nblk: int, kv: int,
+                  d: int, window: Optional[int], softcap: Optional[float],
                   scale: float):
-    jb = pl.program_id(2)
+    jb = pl.program_id(1)
 
     @pl.when(jb == 0)
     def _():
@@ -119,36 +122,39 @@ def _paged_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, qp_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (Sq*G, d)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # (Sq*G, bs)
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
     # block j of the table holds token positions [j*bs, (j+1)*bs); the pool
     # block it maps to was selected by the BlockSpec index_map (scalar
     # prefetch), so masking is purely positional.  Query positions arrive
     # pre-expanded to one row per (chunk token, group) pair; rows < 0 are
     # padding (fully masked → zero output, discarded by the caller).
-    kpos = jb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
-    qrow = qp_ref[0][:, None]                            # (Sq*G, 1)
-    valid = (qrow >= 0) & (kpos[None, :] <= qrow)
+    kpos = jb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+    qrow = qp_ref[0]                                     # (Sq*G, 1)
+    valid = (qrow >= 0) & (kpos <= qrow)
     if window:
-        valid &= kpos[None, :] > qrow - window
-    s = jnp.where(valid, s, NEG)
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_cur)
-    alpha = jnp.exp(m_prev - m_cur)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v_ref[0, :, 0].astype(jnp.float32),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_cur
+        valid &= kpos > qrow - window
+    # one grid step holds one pool block for every KV head: head h is the
+    # lane slice [h*d, (h+1)*d) of the flattened (bs, KV*d) block
+    for h in range(kv):
+        q = q_ref[0, h].astype(jnp.float32) * scale      # (Sq*G, d)
+        k = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)   # (bs, d)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (Sq*G, bs)
+        if softcap:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(valid, s, NEG)
+        m_prev = m_ref[h]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_cur)
+        alpha = jnp.exp(m_prev - m_cur)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+            p, v_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_cur
 
     @pl.when(jb == nblk - 1)
     def _():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _copy_block_kernel(idx_ref, pool_ref, out_ref):
@@ -212,7 +218,7 @@ def paged_decode_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
     the gather never materializes a contiguous per-request cache.
     """
     B, Sq, H, D = q.shape
-    bs, KV = kp.shape[1], kp.shape[2]
+    NB, bs, KV = kp.shape[0], kp.shape[1], kp.shape[2]
     nblk = bt.shape[1]
     G = H // KV
     if qpos is None:
@@ -220,33 +226,38 @@ def paged_decode_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
     # rows ordered (chunk token, group): row r ↔ token r // G, group r % G
     qt = (q.reshape(B, Sq, KV, G, D).transpose(0, 2, 1, 3, 4)
           .reshape(B, KV, Sq * G, D))
-    # expand positions to one entry per kernel row (host-side repeat keeps
-    # the kernel body free of gathers/reshapes Mosaic dislikes)
-    qpe = jnp.repeat(qpos.astype(jnp.int32), G, axis=1)   # (B, Sq*G)
-    kern = functools.partial(_paged_kernel, bs=bs, nblk=nblk, window=window,
-                             softcap=softcap, scale=D ** -0.5)
+    # expand positions to one (Sq*G, 1) column per row (host-side repeat
+    # keeps the kernel body free of gathers/reshapes Mosaic dislikes)
+    qpe = jnp.repeat(qpos.astype(jnp.int32), G, axis=1)[:, :, None]
+    # the pool's (KV, D) minor dims merge into one lane dim (a free
+    # reshape): each grid step DMAs one whole (bs, KV*D) pool block
+    kf = kp.reshape(NB, bs, KV * D)
+    vf = vp.reshape(NB, bs, KV * D)
+    kern = functools.partial(_paged_kernel, bs=bs, nblk=nblk, kv=KV, d=D,
+                             window=window, softcap=softcap,
+                             scale=D ** -0.5)
     R = Sq * G
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, nblk),
+        grid=(B, nblk),
         in_specs=[
-            pl.BlockSpec((1, 1, R, D), lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, R), lambda b, h, j, tbl, ln: (b, 0)),
+            pl.BlockSpec((1, KV, R, D), lambda b, j, tbl, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, KV * D),
+                         lambda b, j, tbl, ln: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, bs, KV * D),
+                         lambda b, j, tbl, ln: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, R, 1), lambda b, j, tbl, ln: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, R, D),
-                               lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),
-                        pltpu.VMEM((R, 1), jnp.float32),
-                        pltpu.VMEM((R, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, KV, R, D),
+                               lambda b, j, tbl, ln: (b, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((KV, R, 1), jnp.float32),
+                        pltpu.VMEM((KV, R, 1), jnp.float32),
+                        pltpu.VMEM((KV, R, D), jnp.float32)],
     )
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, R, D), q.dtype),
         interpret=interpret)(
-        bt.astype(jnp.int32), lens.astype(jnp.int32), qt, kp, vp, qpe)
+        bt.astype(jnp.int32), lens.astype(jnp.int32), qt, kf, vf, qpe)
     return (out.reshape(B, KV, Sq, G, D).transpose(0, 2, 1, 3, 4)
             .reshape(B, Sq, H, D))

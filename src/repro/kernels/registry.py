@@ -249,6 +249,9 @@ def plan_kernel(plan, op: str, **facts) -> Optional[Tuple[Callable, bool]]:
         DISPATCH_REJECTIONS[key] = DISPATCH_REJECTIONS.get(key, 0) + 1
         METRICS.counter("kernels.dispatch.rejections").inc()
         return None
+    # counted at trace time, once per call site a jitted program lowers
+    # through the kernel: the evidence that a plan's Pallas entries ran
+    METRICS.counter(f"kernels.dispatch.{resolved}.{op}").inc()
     return impl.fn, resolved == "pallas_interpret"
 
 

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import flow as rflow
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import FlowConfig, ShapeConfig
 from repro.serving import (Engine, EngineConfig, load_requests_jsonl,
                            synthetic_requests)
@@ -114,6 +115,7 @@ def _run_replay(args) -> None:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true")

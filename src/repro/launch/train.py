@@ -14,6 +14,7 @@ import argparse
 import jax
 
 from repro import flow as rflow
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import FlowConfig, ShapeConfig
 from repro.data.pipeline import DataConfig, SyntheticImages, SyntheticLM
 from repro.optim.adamw import AdamW
@@ -21,6 +22,7 @@ from repro.train.trainer import Trainer, TrainerConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true")

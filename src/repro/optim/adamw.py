@@ -35,7 +35,9 @@ class AdamW:
     min_lr_frac: float = 0.1
 
     def init(self, params) -> AdamWState:
-        z = lambda p: jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), p)
+        # zeros_like keeps each parameter's sharding: moments mirror params
+        z = lambda p: jax.tree.map(
+            lambda x: jnp.zeros_like(x, dtype=jnp.float32), p)
         err = z(params) if self.compress else None
         return AdamWState(jnp.zeros((), jnp.int32), z(params), z(params), err)
 

@@ -82,19 +82,10 @@ class Trainer:
 
     # -- setup ----------------------------------------------------------------
     def init(self, rng) -> tuple:
+        # a mesh plan's params are born on their shards, and the optimizer
+        # moments inherit those shardings
         params = lowering.init_params(self.plan, rng)
-        opt_state = self.opt.init(params)
-        if self.rules is not None:
-            psh = self.rules.params_shardings(self.plan)
-            params = jax.tree.map(jax.device_put, params, psh)
-            osh = AdamWState(
-                jax.device_put(opt_state.step),
-                jax.tree.map(jax.device_put, opt_state.mu, psh),
-                jax.tree.map(jax.device_put, opt_state.nu, psh),
-                None if opt_state.err is None else
-                jax.tree.map(jax.device_put, opt_state.err, psh))
-            opt_state = osh
-        return params, opt_state
+        return params, self.opt.init(params)
 
     def compile_step(self, microbatches: int = 1):
         fn = make_train_step(self.plan, self.opt, microbatches)

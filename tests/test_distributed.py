@@ -18,6 +18,14 @@ def run_sub(body: str, ndev: int = 8, timeout: int = 900) -> str:
         import sys
         sys.path.insert(0, {repr(os.path.join(ROOT, 'src'))})
         sys.path.insert(0, {repr(ROOT)})
+        import jax
+
+        def make_mesh(shape, names):
+            # Auto axes: the flow places arrays through sharding
+            # constraints, which Explicit axes (JAX's default) refuse
+            return jax.make_mesh(
+                shape, names,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(names))
     """) + textwrap.dedent(body)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=timeout)
@@ -35,7 +43,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.distributed.sharding import ShardingRules
         cfg = get_smoke("llama3.2-1b")
         shape = ShapeConfig("s", "train", 16, 4)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = ShardingRules(mesh, dp=("data",))
         flow = FlowConfig(mode="folded", precision="fp32")
         plan_s = build_plan(cfg, flow, shape, mesh_axes=("data", "model"),
@@ -72,7 +80,7 @@ def test_pipeline_loss_matches_folded():
         import dataclasses
         cfg = dataclasses.replace(cfg, n_layers=4)
         shape = ShapeConfig("s", "train", 16, 4)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         flow = FlowConfig(mode="folded", precision="fp32", remat="none",
                           pp_axis="pod",
                           mesh_split=(("pod", 2), ("data", 2), ("model", 2)))
@@ -114,7 +122,7 @@ def test_moe_shard_map_parity():
         for arch in ("mixtral-8x7b", "deepseek-moe-16b"):
             cfg = get_smoke(arch)
             shape = ShapeConfig("s", "train", 16, 4)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             rules = ShardingRules(mesh, dp=("data",))
             flow = FlowConfig(mode="folded", precision="fp32")
             plan_s = build_plan(cfg, flow, shape, mesh_axes=("data", "model"),
@@ -141,7 +149,7 @@ def test_dryrun_cell_small_mesh():
     out = run_sub("""
         import jax
         from repro.launch.dryrun import run_cell
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         r = run_cell("llama3.2-1b", "decode_32k", mesh=mesh)
         assert r["memory"]["per_device_bytes"] > 0
         assert r["hlo"]["collective_bytes"] >= 0
@@ -156,8 +164,8 @@ def test_elastic_checkpoint_reshard():
         import jax, jax.numpy as jnp, tempfile, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train import checkpoint as ckpt
-        m1 = jax.make_mesh((2, 4), ("data", "model"))
-        m2 = jax.make_mesh((4, 2), ("data", "model"))
+        m1 = make_mesh((2, 4), ("data", "model"))
+        m2 = make_mesh((4, 2), ("data", "model"))
         x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
         xs = jax.device_put(x, NamedSharding(m1, P("data", "model")))
         d = tempfile.mkdtemp()
@@ -238,7 +246,7 @@ def test_multipod_mesh_axes():
         from repro.launch.mesh import make_production_mesh
         # only 8 host devices: build the small analogue directly
         import jax
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         assert tuple(mesh.axis_names) == ("pod", "data", "model")
         print("MESH OK")
     """)

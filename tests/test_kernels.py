@@ -104,7 +104,10 @@ def test_decode_attention_rolling(spec):
 
 @pytest.mark.parametrize("spec", [(2, 4, 2, 32, 4, 5, None),
                                   (3, 8, 4, 16, 8, 3, 12),
-                                  (1, 4, 1, 64, 16, 2, None)])
+                                  (1, 4, 1, 64, 16, 2, None),
+                                  # llama3.2-1b head geometry: 8 KV heads
+                                  # as lane slices of a (16, 512) block
+                                  (2, 32, 8, 64, 16, 2, None)])
 def test_paged_decode_attention(spec):
     """The serving subsystem's block-table gather kernel (scalar-prefetch
     index_map) vs the registered ref fallback, heterogeneous row lengths."""
@@ -197,6 +200,9 @@ def test_conv2d_tile_tuple_forwards_both_components(monkeypatch):
     (1, 17, 17, 4, 16, 5, 2, "SAME", False),
     (2, 12, 12, 8, 8, 1, 1, "VALID", True),    # the MobileNet 1x1 workhorse
     (1, 16, 16, 3, 6, 3, 2, "VALID", False),
+    (1, 23, 23, 3, 16, 7, 2, "SAME", True),     # ResNet stem: 7x7/2, CI=3
+    (2, 7, 7, 16, 8, 3, 1, "SAME", True),      # 7 output columns (stage 4)
+    (1, 14, 14, 8, 16, 1, 2, "SAME", True),    # strided 1x1 projection
 ])
 def test_conv2d(spec):
     N, H, W, CI, CO, k, s, pad, bn = spec
