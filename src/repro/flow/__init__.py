@@ -37,8 +37,13 @@ from repro.core import lowering
 from repro.core.plan import ExecutionPlan, _build_plan
 from repro.distributed.meshspec import MeshSpec
 from repro.obs import TRACER
+from repro.obs.trace import bridge
 
 __all__ = ["compile", "CompiledModel", "MeshSpec"]
+
+# every repro.obs span also lands in a JAX profiler session's trace, on the
+# profiler's clock, while one records
+bridge(jax.profiler.TraceAnnotation.is_enabled, jax.profiler.TraceAnnotation)
 
 
 class _nullcontext:
@@ -113,17 +118,22 @@ class CompiledModel:
 
     def _wrap_timed(self, name: str, jfn: Callable) -> Callable:
         """Record the wall-clock of the stage's first call (trace + XLA
-        compile) into ``stats['stages']``."""
+        compile) into ``stats['stages']``, and run every call in a
+        ``model.<stage>`` span: the host side of the call, which returns
+        once the work is dispatched."""
+        label = "model." + name.split("[")[0]
+
         def fn(*args, **kw):
-            st = self.stats["stages"]
-            if name not in st:
-                sp = TRACER.timed(f"stage.{name}", cat="stage")
-                out = jfn(*args, **kw)
-                jax.block_until_ready(out)
-                sp.end()
-                st[name] = {"first_call_s": round(sp.elapsed_s, 4)}
-                return out
-            return jfn(*args, **kw)
+            with TRACER.span(label, cat="model"):
+                st = self.stats["stages"]
+                if name not in st:
+                    sp = TRACER.timed(f"stage.{name}", cat="stage")
+                    out = jfn(*args, **kw)
+                    jax.block_until_ready(out)
+                    sp.end()
+                    st[name] = {"first_call_s": round(sp.elapsed_s, 4)}
+                    return out
+                return jfn(*args, **kw)
         return fn
 
     def _stage(self, name: str, build: Callable[[], Callable]) -> Callable:
@@ -300,9 +310,9 @@ class CompiledModel:
         the DSE's measured-time validator (``validate="measure"``) — the
         on-device confirmation the paper got from hours of place & route.
 
-        ``trace_dir`` brackets the timed loop in ``jax.profiler.trace`` so
-        a device profile lines up with the host-side ``measure.step``
-        spans the module tracer records (``repro.obs``).
+        ``trace_dir`` brackets the timed loop in ``jax.profiler.trace``;
+        the host-side ``measure.step`` spans land in that profile beside
+        the device's operations (``repro.obs`` profiler bridge).
         """
         stage = stage if stage is not None else self.shape.kind
         B = self.shape.global_batch

@@ -9,10 +9,12 @@ observability layer they all publish into:
 
 * :class:`~repro.obs.trace.Tracer` — nested spans with attributes in a
   bounded ring buffer; thread-safe; a **no-op when disabled** (one boolean
-  check on the hot path).  Context-manager (``with tracer.span(...)``),
-  explicit (``sp = tracer.span(...); sp.end()``) and decorator
-  (``@tracer.trace()``) APIs.  Exports Chrome trace-event JSON (loads in
-  Perfetto / ``chrome://tracing``) and a JSONL event log.
+  check on the hot path).  Context-manager (``with tracer.span(...)``)
+  and explicit (``sp = tracer.span(...); sp.end()``) APIs.  Exports Chrome
+  trace-event JSON (loads in Perfetto / ``chrome://tracing``) and a JSONL
+  event log.  :func:`~repro.obs.trace.bridge` mirrors every span into a
+  profiler while one records (``repro.flow`` installs
+  ``jax.profiler.TraceAnnotation``), ring buffer on or off.
 * :class:`~repro.obs.metrics.MetricsRegistry` — typed counters, gauges and
   histograms under stable dotted names (``serving.prefix.hits``,
   ``pool.blocks.live``, ``kernels.dispatch.rejections``, …).  The serving

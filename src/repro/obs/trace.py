@@ -10,42 +10,73 @@ becomes one Chrome trace-event dict (``ph="X"`` complete event with
 ``name``/``cat``/``ts``/``dur``/``pid``/``tid``/``args``), so the export
 loads directly in Perfetto or ``chrome://tracing``.
 
+Profiler bridge: once :func:`bridge` is installed (``repro.flow`` installs
+``jax.profiler.TraceAnnotation``), every span of every tracer also opens a
+profiler annotation of the same name while a profiler session records,
+whether or not the ring buffer is on — so the spans land in the device
+profile, on the profiler's clock, beside the device's operations.
+
 Thread-safety: the buffer append and tid interning are lock-protected; the
 span stack is thread-local, so nesting depth is correct per thread.
 """
 from __future__ import annotations
 
-import functools
+import contextlib
 import json
 import threading
 import time
 from collections import deque
-from typing import (Any, Callable, Deque, Dict, Iterator, List, Optional,
-                    TypeVar)
-
-_F = TypeVar("_F", bound=Callable[..., Any])
+from typing import (Any, Callable, ContextManager, Deque, Dict, Iterator, List,
+                    Optional)
 
 #: One exported trace event (Chrome trace-event "complete" format).
 Event = Dict[str, Any]
+
+
+def _off() -> bool:
+    return False
+
+
+# the profiler bridge (see bridge()): whether a profiler session records,
+# and the annotation a span opens in it
+_profiling: Callable[[], bool] = _off
+_annotation: Callable[[str], ContextManager[Any]] = contextlib.nullcontext
+
+
+def bridge(is_active: Callable[[], bool],
+           annotation: Callable[[str], ContextManager[Any]]) -> None:
+    """Mirror spans into a profiler: while ``is_active()`` is true, each
+    span that :meth:`Tracer.span` or :meth:`Tracer.timed` opens also enters
+    ``annotation(name)`` and exits it when the span ends.  This package
+    imports no profiler; the code that imports one installs it."""
+    global _profiling, _annotation
+    _profiling, _annotation = is_active, annotation
 
 
 class Span:
     """One open span.  Usable as a context manager or ended explicitly via
     :meth:`end` (idempotent — the first call wins); ``set()`` attaches
     attributes at any point before the end.  ``elapsed_s`` is valid after
-    the span has ended (and live-reads while it is still open)."""
+    the span has ended (and live-reads while it is still open).  A span
+    records into the ring buffer when ``record``, and opens a profiler
+    annotation when ``profiled``."""
 
     __slots__ = ("_tracer", "_record", "name", "cat", "attrs", "_t0",
-                 "_t_end", "depth", "_ended")
+                 "_t_end", "depth", "_ended", "_mark")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 attrs: Dict[str, Any], record: bool) -> None:
+                 attrs: Dict[str, Any], record: bool,
+                 profiled: bool) -> None:
         self._tracer = tracer
         self._record = record
         self.name = name
         self.cat = cat
         self.attrs = attrs
         self.depth = tracer._push(self) if record else 0
+        self._mark: Optional[ContextManager[Any]] = None
+        if profiled:
+            self._mark = _annotation(name)
+            self._mark.__enter__()
         self._t0 = tracer._clock()
         self._t_end: Optional[float] = None
         self._ended = False
@@ -61,6 +92,8 @@ class Span:
             return
         self._ended = True
         self._t_end = self._tracer._clock()
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
         if attrs:
             self.attrs.update(attrs)
         if self._record:
@@ -115,7 +148,9 @@ class Tracer:
     """Bounded, thread-safe span recorder.
 
     * ``enabled=False`` (the default): :meth:`span` returns a shared no-op
-      span after one boolean check — nothing is timed or stored.
+      span after one boolean check and one ask of the profiler bridge —
+      nothing is timed or stored — unless a profiler records, when the span
+      is timed and annotated but not stored.
     * ``max_events`` bounds the ring buffer: the newest events win, the
       oldest are dropped (``n_dropped`` counts them).
     * ``clock`` is injectable (defaults to ``time.perf_counter``) so span
@@ -187,34 +222,20 @@ class Tracer:
 
     # -- public API ----------------------------------------------------------
     def span(self, name: str, cat: str = "span", **attrs: Any) -> Span:
-        """Open a recorded span — or the shared no-op span when disabled
-        (the hot-path contract: one boolean check, no clock read)."""
-        if not self.enabled:
+        """Open a span, recorded while enabled and annotated while a
+        profiler records — or the shared no-op span when neither (the
+        hot-path contract: one boolean check, one ask of the bridge, no
+        clock read)."""
+        profiled = _profiling()
+        if not self.enabled and not profiled:
             return _NOOP
-        return Span(self, name, cat, attrs, record=True)
+        return Span(self, name, cat, attrs, self.enabled, profiled)
 
     def timed(self, name: str, cat: str = "timed", **attrs: Any) -> Span:
         """Open an always-wall-clocked span, recorded only while enabled —
         the one-code-path replacement for hand-rolled stopwatch blocks:
         ``elapsed_s`` is valid whether or not tracing is on."""
-        return Span(self, name, cat, attrs, record=self.enabled)
-
-    def trace(self, name: Optional[str] = None,
-              cat: str = "fn") -> Callable[[_F], _F]:
-        """Decorator form: the wrapped call runs inside a span (named after
-        the function unless overridden); zero overhead beyond one boolean
-        check while disabled."""
-        def deco(fn: _F) -> _F:
-            label = name if name is not None else fn.__qualname__
-
-            @functools.wraps(fn)
-            def wrapper(*args: Any, **kw: Any) -> Any:
-                if not self.enabled:
-                    return fn(*args, **kw)
-                with self.span(label, cat=cat):
-                    return fn(*args, **kw)
-            return wrapper  # type: ignore[return-value]
-        return deco
+        return Span(self, name, cat, attrs, self.enabled, _profiling())
 
     def clear(self) -> None:
         with self._lock:

@@ -463,6 +463,7 @@ class Engine:
                 logits, pstate, _ = self.compiled.prefill(
                     self.params, {"tokens": jnp.asarray(tokens),
                                   "positions": jnp.asarray(positions)})
+                sp_sync = tr.span("engine.sync", cat="sub")
                 if spec_on and e.temperature > 0:
                     # per-request rng streams: the first generated token is
                     # commit index 0 of its request's stream, so prefilled
@@ -478,6 +479,7 @@ class Engine:
                     rng, k = jax.random.split(rng)
                     toks = np.asarray(
                         self._sample(logits[:, -1], k, e.temperature))
+                sp_sync.end()
                 host_syncs += 1
                 for i, a in enumerate(adm):
                     cache.admit(a.slot, a.request.prompt_len,
@@ -500,10 +502,11 @@ class Engine:
                     stall = "no-free-slot" \
                         if not any(s.free for s in sched.slots) \
                         else "no-free-kv-blocks"
-                sp_admit.end(admitted=len(admitted),
+                sp_admit.set(admitted=len(admitted),
                              pool_live=cache.pool.used_blocks,
                              pool_free=cache.pool.free_blocks,
                              **({"stall": stall} if stall else {}))
+            sp_admit.end()
 
             # 2. advance the occupied slots (batch-bucketed): a host-free
             #    fori segment when nothing can interrupt it, otherwise one
@@ -535,12 +538,14 @@ class Engine:
                 sp_cow = tr.span("cow-fork", cat="sub")
                 cache.prepare_decode(active)   # COW forks before any write
                 sp_cow.end()
+                sp_prep = tr.span("engine.prepare", cat="sub")
                 tok0 = np.zeros(B, np.int32)
                 pos0 = np.zeros(B, np.int32)
                 for i in active:
                     tok0[i] = sched.slots[i].last_token
                     pos0[i] = sched.slots[i].pos
                 part = slice_state(cache.state, cache.slot_axes, B)
+                sp_prep.end()
                 seg = self.compiled.decode_segment(
                     T, temperature=e.temperature)
                 toks_dev, new_part, rng = seg(
@@ -549,8 +554,11 @@ class Engine:
                 cache.state = merge_state(cache.state, new_part,
                                           cache.slot_axes, B)
                 cache.note_decode_tick(active, {i: T for i in active})
+                sp_sync = tr.span("engine.sync", cat="sub")
                 toks = np.asarray(toks_dev)    # ONE host sync for T tokens
+                sp_sync.end()
                 host_syncs += 1
+                sp_commit = tr.span("engine.commit", cat="sub")
                 for i in active:
                     s = sched.slots[i]
                     stop = s.request.stop_token
@@ -558,13 +566,15 @@ class Engine:
                         sched.record_token(i, int(toks[i, t]))
                         if stop is not None and int(toks[i, t]) == stop:
                             break
+                sp_commit.end()
                 ticks += T
                 fori_segments += 1
                 note_pool()
                 evict_finished()
                 if tr.enabled:
-                    sp_fori.end(pool_live=cache.pool.used_blocks,
+                    sp_fori.set(pool_live=cache.pool.used_blocks,
                                 host_syncs=host_syncs)
+                sp_fori.end()
                 continue
 
             # 2b. one decode tick over the occupied slots.  Slots catching
@@ -604,6 +614,7 @@ class Engine:
             sp_cow = tr.span("cow-fork", cat="sub")
             cache.prepare_decode(active)       # COW forks before any write
             sp_cow.end()
+            sp_prep = tr.span("engine.prepare", cat="sub")
             need = max((len(proposals[i]) + 1 if i in proposals
                         else min(len(sched.slots[i].pending), e.chunk_size)
                         for i in active), default=1)
@@ -654,6 +665,7 @@ class Engine:
                         positions[s.index, 0] = s.pos
                         fills[s.index] = 1
             part = slice_state(cache.state, cache.slot_axes, B)
+            sp_prep.end()
             logits, new_part, _ = self.compiled.decode(
                 self.params, {"tokens": jnp.asarray(tokens),
                               "positions": jnp.asarray(positions)},
@@ -661,6 +673,7 @@ class Engine:
             cache.state = merge_state(cache.state, new_part,
                                       cache.slot_axes, B)
             cache.note_decode_tick(active, fills)
+            sp_sync = tr.span("engine.sync", cat="sub")
             if spec_on:
                 # every column's target token at once: column c of row i is
                 # the token the target model emits at commit index
@@ -692,7 +705,9 @@ class Engine:
                 last_lg = jnp.take_along_axis(
                     logits, jnp.asarray(sel)[:, None, None], axis=1)[:, 0]
                 toks = np.asarray(self._sample(last_lg, k, e.temperature))
+            sp_sync.end()
             host_syncs += 1
+            sp_commit = tr.span("engine.commit", cat="sub")
             spec_commits: Dict[int, int] = {}
             for sidx in active:
                 s = sched.slots[sidx]
@@ -749,14 +764,16 @@ class Engine:
                 # for every rolled-back slot (must precede eviction — the
                 # prefix index only ever sees committed tokens)
                 cache.spec_commit_many(spec_commits)
+            sp_commit.end()
             if proposals:
                 spec_ticks += 1
             ticks += 1
             note_pool()
             evict_finished()
             if tr.enabled:
-                sp_tick.end(pool_live=cache.pool.used_blocks,
+                sp_tick.set(pool_live=cache.pool.used_blocks,
                             host_syncs=host_syncs)
+            sp_tick.end()
 
         run_sp.end(ticks=ticks, host_syncs=host_syncs)
         wall = self.clock() - t0

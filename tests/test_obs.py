@@ -1,17 +1,27 @@
 """repro.obs: tracer span semantics, ring-buffer bounding, Chrome export
-schema, metrics registry typing, engine tick timelines, and the exactness
-gates (byte-identical outputs traced vs untraced, <2% disabled overhead)."""
+schema, metrics registry typing, engine tick timelines, the profiler bridge
+(spans in a JAX profile, reduced as the benchmark reduces it), and the
+exactness gates (byte-identical outputs traced vs untraced and profiled vs
+not, <2% disabled overhead)."""
 import json
 import math
+import os
+import subprocess
+import sys
 import time
+from types import SimpleNamespace
 
 import jax
+import numpy as np
 import pytest
 
+from bench import devtrace
+from bench.harness import reader
 from repro import flow as rflow
 from repro.configs.base import FlowConfig, ShapeConfig
 from repro.launch.obs import summarize
 from repro.obs import Counter, Gauge, Histogram, MetricsRegistry, Tracer
+from repro.obs import trace as trace_mod
 from repro.obs.trace import load_trace
 from repro.serving.engine import Engine, EngineConfig
 from repro.serving.scheduler import synthetic_requests
@@ -80,16 +90,74 @@ def test_ring_buffer_bounds_and_drop_count():
     assert len(tr) == 0 and tr.n_dropped == 0
 
 
-def test_decorator_form():
+def test_span_off_and_unprofiled_is_shared_noop_reading_no_clock():
+    # repro.flow (imported above) has installed the JAX bridge; no profiler
+    # records here, and the ring buffer is off
+    reads = []
+
+    def clock():
+        reads.append(1)
+        return float(len(reads))
+
+    tr = Tracer(enabled=False, clock=clock)
+    reads.clear()
+    sp = tr.span("a", k=1)
+    sp.set(x=2)
+    sp.end()
+    assert sp is trace_mod._NOOP
+    assert reads == []
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    """A bridge to a profiler that always records, logging what each span
+    annotates."""
+    log = []
+
+    class Mark:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(trace_mod, "_profiling", lambda: True)
+    monkeypatch.setattr(trace_mod, "_annotation", Mark)
+    return log
+
+
+def test_profiled_only_span_annotates_and_stores_nothing(fake_profiler):
+    tr = Tracer(enabled=False)
+    with tr.span("outer"):
+        sp = tr.timed("inner")
+        sp.end()
+        sp.end()                 # a second end annotates nothing more
+    assert sp.elapsed_s >= 0
+    assert len(tr) == 0
+    assert fake_profiler == [("enter", "outer"), ("enter", "inner"),
+                             ("exit", "inner"), ("exit", "outer")]
+
+
+def test_recorded_span_also_annotates_while_profiled(fake_profiler):
     tr = Tracer(enabled=True)
-
-    @tr.trace()
-    def work(a, b):
-        return a + b
-
-    assert work(2, 3) == 5
+    tr.span("s", k=1).end()
     (ev,) = tr.events()
-    assert ev["name"].endswith("work") and ev["cat"] == "fn"
+    assert ev["name"] == "s" and ev["args"] == {"k": 1}
+    assert fake_profiler == [("enter", "s"), ("exit", "s")]
+
+
+def test_repro_obs_imports_no_jax():
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = ("import sys; import repro.obs; "
+            "print(sorted(m for m in sys.modules if m.split('.')[0] in "
+            "('jax', 'jaxlib', 'numpy')))")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True,
+                         env=dict(os.environ, PYTHONPATH=src))
+    assert out.stdout.strip() == "[]"
 
 
 def test_chrome_export_schema(tmp_path):
@@ -253,7 +321,8 @@ def test_trace_phases_chunked_and_spec(served):
 
 
 def test_disabled_tracer_overhead_under_2pct(served):
-    # the disabled hot path is one boolean check per span site; bound the
+    # the disabled hot path is one boolean check and one ask of the
+    # profiler bridge per span site; bound the
     # replay's total span cost by microbenchmarking that path and scaling
     # by the replay's span-site count, instead of racing two wall-clocks
     cm, params, reqs = served
@@ -265,9 +334,10 @@ def test_disabled_tracer_overhead_under_2pct(served):
     for _ in range(n):
         tr.span("x")
     per_call = (time.perf_counter() - t0) / n
-    # <= ~6 disabled span sites per tick (admit, tick, cow, evict, + ends)
-    sites = 6 * (report.metrics["decode_ticks"]
-                 + report.metrics["prefill_batches"] + 2)
+    # <= ~10 disabled span sites per tick (admit, tick, cow, prepare,
+    # model call, sync, commit, evict, + the admission's)
+    sites = 10 * (report.metrics["decode_ticks"]
+                  + report.metrics["prefill_batches"] + 2)
     assert sites * per_call < 0.02 * wall
 
 
@@ -316,6 +386,99 @@ def test_summarize_cli(tmp_path, served, capsys):
     assert main(["summarize", path]) == 0
     out = capsys.readouterr().out
     assert "phase" in out and "admit" in out and "coverage" in out
+
+
+# ---------------------------------------------------------------------------
+# profiler bridge: the spans in a JAX profile, reduced as the benchmark does
+# ---------------------------------------------------------------------------
+
+def _profiled(tmp_path, fn):
+    """``fn()`` under the JAX profiler inside the benchmark's window
+    annotation; its result and the reduced trace."""
+    jax.profiler.start_trace(str(tmp_path), profiler_options=devtrace.options())
+    try:
+        with jax.profiler.TraceAnnotation(devtrace.WINDOW):
+            out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    return out, devtrace.load(str(tmp_path))
+
+
+def _named(tr, name):
+    return [(s, e) for s, e, n in tr.host if n == name]
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_engine_spans_reach_the_profile(tmp_path, served):
+    cm, params, reqs = served
+    _, r_off = _run(cm, params, reqs)
+    (eng, report), tr = _profiled(tmp_path,
+                                  lambda: _run(cm, params, reqs))
+    assert [r.tokens for r in report.results] == \
+           [r.tokens for r in r_off.results]
+    assert len(eng.tracer) == 0          # the ring buffer stayed off
+    snap = report.registry.snapshot()
+    assert snap["serving.fori.segments"] == 0
+    runs = _named(tr, "engine.run")
+    ticks = _named(tr, "tick.decode")
+    assert len(runs) == 1 and _inside(runs[0], tr.window)
+    assert len(ticks) == snap["serving.ticks"] > 0
+    assert len(_named(tr, "engine.sync")) == snap["serving.host_syncs"]
+    assert len(_named(tr, "model.prefill")) == \
+        snap["serving.prefill.batches"]
+    assert len(_named(tr, "tick.admit")) >= 1
+    # engine.run > tick.decode > one of each step of the tick
+    for tick in ticks:
+        assert _inside(tick, runs[0])
+        for name in ("cow-fork", "engine.prepare", "model.decode",
+                     "engine.sync", "engine.commit", "evict"):
+            assert sum(_inside(sp, tick) for sp in _named(tr, name)) == 1, \
+                name
+    assert reader("decode_tick_ms.decode")(SimpleNamespace(trace=tr)) > 0
+    # no device plane on the CPU: nothing to measure idle against
+    assert reader("tick_idle_ms.decode")(SimpleNamespace(trace=tr)) is None
+
+
+def test_fori_segments_reach_the_profile(tmp_path, served):
+    cm, params, reqs = served
+    (_, report), tr = _profiled(tmp_path,
+                                lambda: _run(cm, params, reqs, fori_seg=2))
+    snap = report.registry.snapshot()
+    fori = _named(tr, "tick.fori")
+    assert len(fori) == snap["serving.fori.segments"] > 0
+    assert len(_named(tr, "engine.sync")) == snap["serving.host_syncs"]
+    for seg in fori:
+        for name in ("engine.prepare", "model.decode_segment",
+                     "engine.sync", "engine.commit"):
+            assert sum(_inside(sp, seg) for sp in _named(tr, name)) == 1, \
+                name
+
+
+def test_cnn_prefill_spans_reach_the_profile(tmp_path):
+    cm = rflow.compile("lenet5", ShapeConfig("cnn1", "prefill", 1, 1),
+                       FlowConfig(mode="folded", precision="fp32"))
+    params = cm.init_params(jax.random.key(0))
+    cfg = cm.cfg
+    images = jax.numpy.ones((1, cfg.image_size, cfg.image_size,
+                             cfg.image_channels), jax.numpy.float32)
+    prefill = cm.prefill
+    np.asarray(prefill(params, {"images": images})[0])     # compile
+
+    def loop(n=5):
+        return [np.asarray(prefill(params, {"images": images})[0])
+                for _ in range(n)]
+
+    outs, tr = _profiled(tmp_path, loop)
+    calls = _named(tr, "model.prefill")
+    assert len(calls) == len(outs)
+    assert all(_inside(c, tr.window) for c in calls)
+    got = reader("dispatch_us.online")(SimpleNamespace(trace=tr))
+    assert got == pytest.approx(
+        sum(e - s for s, e in calls) / len(calls) * 1e-3)
+    assert got > 0
 
 
 def test_kernel_dispatch_rejections_metric():
