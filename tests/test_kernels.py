@@ -195,7 +195,9 @@ def test_conv2d_tile_tuple_forwards_both_components(monkeypatch):
     assert captured["block_h"] is None and captured["block_c"] == 64
 
 
-@pytest.mark.parametrize("spec", [
+# (N, H, W, CI, CO, k, stride, padding, bn[, tile]); with these small maps
+# the lane-sparse cases take the folded path (``conv2d.folds``)
+CONV_SPECS = [
     (2, 16, 16, 3, 8, 3, 1, "SAME", True),
     (1, 17, 17, 4, 16, 5, 2, "SAME", False),
     (2, 12, 12, 8, 8, 1, 1, "VALID", True),    # the MobileNet 1x1 workhorse
@@ -203,14 +205,98 @@ def test_conv2d_tile_tuple_forwards_both_components(monkeypatch):
     (1, 23, 23, 3, 16, 7, 2, "SAME", True),     # ResNet stem: 7x7/2, CI=3
     (2, 7, 7, 16, 8, 3, 1, "SAME", True),      # 7 output columns (stage 4)
     (1, 14, 14, 8, 16, 1, 2, "SAME", True),    # strided 1x1 projection
-])
-def test_conv2d(spec):
-    N, H, W, CI, CO, k, s, pad, bn = spec
+    (2, 32, 32, 3, 32, 3, 2, "SAME", True),    # MobileNetV1 stem: 3x3/2, CI 3
+    (1, 32, 32, 1, 6, 5, 1, "VALID", False),   # LeNet-5 conv1: 5x5/1, CI 1
+    (1, 40, 40, 3, 16, 7, 2, "SAME", True, (6, 128)),  # stem, block_h 6 ∤ 20
+]
+
+
+def _conv_case(spec):
+    N, H, W, CI, CO, k, s, pad, bn, *tile = spec
     x = jnp.asarray(R.randn(N, H, W, CI), jnp.float32)
     w = jnp.asarray(R.randn(k, k, CI, CO), jnp.float32)
     bnp = tuple(jnp.asarray(R.rand(CO) + 0.5, jnp.float32)
                 for _ in range(4)) if bn else None
     y = ops.conv2d_fused(x, w, stride=s, padding=pad, bn=bnp, act="relu",
-                         interpret=True)
+                         tile=tile[0] if tile else None, interpret=True)
     r = ref.conv2d_fused_ref(x, w, stride=s, padding=pad, bn=bnp, act="relu")
+    return y, r
+
+
+@pytest.mark.parametrize("spec", CONV_SPECS)
+def test_conv2d(spec):
+    y, r = _conv_case(spec)
     assert relerr(y, r) < 1e-5
+
+
+@pytest.mark.parametrize("spec", CONV_SPECS)
+def test_conv2d_tap_path(spec, monkeypatch):
+    """Every shape through the tap path too, where the fold rule would
+    send the lane-sparse ones to the folded path."""
+    from repro.kernels import conv2d as _cv
+    from repro.obs import METRICS
+    monkeypatch.setattr(_cv, "folds", lambda *a: False)
+    folded = METRICS.counter("kernels.conv2d.folded").value
+    y, r = _conv_case(spec)
+    assert relerr(y, r) < 1e-5
+    assert METRICS.counter("kernels.conv2d.folded").value == folded
+
+
+# the distinct convolutions of ResNet-34 at 224 px (He et al. 2015, Table 1;
+# SAME): (H, CI, CO, k, stride), the stem first
+RESNET34_CONVS = [
+    (224, 3, 64, 7, 2),
+    (56, 64, 64, 3, 1), (56, 64, 128, 3, 2), (56, 64, 128, 1, 2),
+    (28, 128, 128, 3, 1), (28, 128, 256, 3, 2), (28, 128, 256, 1, 2),
+    (14, 256, 256, 3, 1), (14, 256, 512, 3, 2), (14, 256, 512, 1, 2),
+    (7, 512, 512, 3, 1),
+]
+
+
+def _folds(h, ci, k, stride, padding="SAME"):
+    from repro.kernels.conv2d import folds
+    ho = -(-h // stride) if padding == "SAME" else (h - k) // stride + 1
+    return folds(k, k, ci, stride, ho, -(-ho // 8) * 8)
+
+
+def test_conv2d_fold_rule():
+    """Shapes alone decide the path: of ResNet-34's convs only the stem
+    folds; the MobileNetV1 stem and LeNet-5's conv1 fold; 1x1 never."""
+    assert [_folds(h, ci, k, s) for h, ci, _, k, s in RESNET34_CONVS] \
+        == [True] + [False] * (len(RESNET34_CONVS) - 1)
+    assert _folds(224, 3, 3, 2)                        # MobileNetV1 stem
+    assert _folds(32, 1, 5, 1, "VALID")                # LeNet-5 conv1
+    for h, ci, s in [(224, 3, 1), (224, 3, 2), (28, 1, 1), (7, 1024, 1)]:
+        assert not _folds(h, ci, 1, s)
+
+
+def test_conv2d_fold_counter():
+    """``kernels.conv2d.folded`` counts folded call sites at trace time:
+    one a traced folded conv, none a tap-path conv, and one for the whole
+    ResNet-34 program (its stem), beside its 18 dispatched conv sites."""
+    from repro import flow
+    from repro.configs.base import FlowConfig, ShapeConfig
+    from repro.configs.resnet34 import CONFIG
+    from repro.obs import METRICS
+    folded = METRICS.counter("kernels.conv2d.folded")
+    dispatched = METRICS.counter("kernels.dispatch.pallas_interpret.conv2d")
+    f32 = jnp.float32
+
+    def traced(x, w, stride):
+        before = folded.value
+        jax.eval_shape(lambda a, b: ops.conv2d_fused(
+            a, b, stride=stride, interpret=True),
+            jax.ShapeDtypeStruct(x, f32), jax.ShapeDtypeStruct(w, f32))
+        return folded.value - before
+
+    assert traced((2, 64, 64, 3), (7, 7, 3, 64), 2) == 1
+    assert traced((2, 14, 14, 64), (3, 3, 64, 64), 1) == 0
+    assert traced((2, 14, 14, 3), (1, 1, 3, 64), 1) == 0
+    cm = flow.compile(CONFIG, ShapeConfig("fold", "prefill", 1, 1),
+                      FlowConfig(mode="folded"), backend="pallas_interpret")
+    f0, d0 = folded.value, dispatched.value
+    out = jax.eval_shape(
+        lambda p, x: cm.apply(p, {"images": x}, mode="prefill")[0],
+        cm.param_shapes(), jax.ShapeDtypeStruct((1, 224, 224, 3), f32))
+    assert out.shape == (1, CONFIG.vocab_size)
+    assert (folded.value - f0, dispatched.value - d0) == (1, 18)

@@ -1,6 +1,7 @@
 """Compile rehearsal: the main path's Pallas kernels compiled for a
 described TPU v5e chip at real widths (llama3.2-1b: H=32, KV=8, D=64,
-block 16; ResNet-34 at 224 px, batch 8; bf16).
+block 16; ResNet-34 at 224 px, the MobileNetV1 stem and LeNet-5's conv1,
+batch 8; bf16).
 
 Nothing runs: each test lowers one kernel through the TPU compiler
 (Mosaic) with ``interpret=False`` and checks that a TPU custom call came
@@ -100,12 +101,15 @@ def test_copy_block(one_chip):
     (56, 64, 128, 3, 2),      # stage 2 entry
     (56, 64, 128, 1, 2),      # stage 2 projection shortcut
     (7, 512, 512, 3, 1),      # stage 4: 7 output columns
+    (224, 3, 32, 3, 2),       # MobileNetV1 stem: folded, K = 27
+    (32, 1, 6, 5, 1),         # LeNet-5 conv1 (VALID): folded, K = 25
 ])
 def test_conv2d_resnet34(one_chip, hw, ci, co, k, stride):
     f32 = jnp.float32
+    padding = "VALID" if (hw, ci) == (32, 1) else "SAME"
 
     def conv(x, w, *bn):
-        return kops.conv2d_fused(x, w, stride=stride, bn=bn, act="relu",
-                                 tile=(8, 128))
+        return kops.conv2d_fused(x, w, stride=stride, padding=padding, bn=bn,
+                                 act="relu", tile=(8, 128))
     _compile(one_chip, conv, ((B, hw, hw, ci), BF), ((k, k, ci, co), BF),
              ((co,), f32), ((co,), f32), ((co,), f32), ((co,), f32))
