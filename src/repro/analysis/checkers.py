@@ -17,7 +17,7 @@ from repro.analysis.diagnostics import (ERROR, WARNING, Diagnostic,
 
 # every key the kernel layer or a pass reads out of ``plan.tiles``
 _TILE_KEYS = ("matmul", "attention", "decode_attention", "conv2d",
-              "wkv_chunk", "ce_chunk")
+              "depthwise_conv2d", "wkv_chunk", "ce_chunk")
 
 
 # ---------------------------------------------------------------------------

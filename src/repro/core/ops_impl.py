@@ -861,13 +861,16 @@ def op_image_in(ctx: Ctx, op, p, h):
 # CNN ops
 # ---------------------------------------------------------------------------
 
-def _conv_backend(ctx: Ctx, x, w, *, stride, padding, groups=1,
-                  bn=None, act=None):
-    kern = plan_kernel(ctx.plan, "conv2d", groups=groups)
+def _conv_backend(ctx: Ctx, kernel: str, x, w, *, stride, padding,
+                  groups=1, bn=None, act=None):
+    """``kernel`` (``conv2d`` or ``depthwise_conv2d``) where the plan's
+    backend table and the call site allow it, else the reference conv."""
+    kern = plan_kernel(ctx.plan, kernel, groups=groups, x=x, w=w,
+                       stride=stride, padding=padding)
     if kern is not None:
         fn, interpret = kern
         return fn(x, w, stride=stride, padding=padding, bn=bn, act=act,
-                  interpret=interpret, tile=ctx.plan.tiles.get("conv2d"))
+                  interpret=interpret, tile=ctx.plan.tiles.get(kernel))
     dt = ctx.compute_dtype
     # mixed-precision conv transpose rules reject bf16 operands with an f32
     # preferred type; the reference path upcasts instead (the Pallas kernel
@@ -895,20 +898,21 @@ def _bn_params(p, prefix=""):
 def op_conv2d(ctx: Ctx, op, p, x):
     w = next(v for k, v in p.items() if k.endswith("_w"))
     bn = _bn_params(p) if op.attrs.get("bn") else None
-    return _conv_backend(ctx, x, w, stride=op.attrs.get("stride", 1),
+    return _conv_backend(ctx, "conv2d", x, w,
+                         stride=op.attrs.get("stride", 1),
                          padding=op.attrs.get("padding", "SAME"),
                          bn=bn, act=op.attrs.get("act"))
 
 
 def op_depthwise_conv2d(ctx: Ctx, op, p, x):
+    """One ``(kh, kw)`` filter per channel; the weight is ``(kh, kw, 1, C)``,
+    the HWIO filter of a conv with ``feature_group_count=C``."""
     w = next(v for k, v in p.items() if k.endswith("_w"))
-    C = x.shape[-1]
-    kh, kw, _, _ = w.shape
-    wg = w.reshape(kh, kw, 1, C)
     bn = _bn_params(p) if op.attrs.get("bn") else None
-    return _conv_backend(ctx, x, wg, stride=op.attrs.get("stride", 1),
-                         padding=op.attrs.get("padding", "SAME"), groups=C,
-                         bn=bn, act=op.attrs.get("act"))
+    return _conv_backend(ctx, "depthwise_conv2d", x, w,
+                         stride=op.attrs.get("stride", 1),
+                         padding=op.attrs.get("padding", "SAME"),
+                         groups=x.shape[-1], bn=bn, act=op.attrs.get("act"))
 
 
 def op_batchnorm(ctx: Ctx, op, p, x):

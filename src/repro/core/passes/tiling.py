@@ -81,7 +81,7 @@ def select_attention_tile(seq_q: int, seq_k: int, head_dim: int, *,
 #: Every key the tile table may carry — the valid targets of
 #: ``FlowConfig.tile_overrides`` (the flow-knob screen rejects others).
 TILE_KEYS = ("matmul", "attention", "decode_attention", "conv2d",
-             "wkv_chunk", "ce_chunk")
+             "depthwise_conv2d", "wkv_chunk", "ce_chunk")
 
 
 def apply_overrides(tiles: Dict[str, object], flow) -> Dict[str, str]:
@@ -112,6 +112,7 @@ def run(cfg, shape, flow) -> Dict[str, object]:
         tiles["attention"] = (128, 128)
         tiles["decode_attention"] = 512
         tiles["conv2d"] = (8, 128)
+        tiles["depthwise_conv2d"] = (8, 128)
         tiles["wkv_chunk"] = 16
         tiles["ce_chunk"] = flow.ce_chunk
         apply_overrides(tiles, flow)
@@ -126,6 +127,10 @@ def run(cfg, shape, flow) -> Dict[str, object]:
             max(seq, 8), skv, cfg.attention.head_dim, vmem=vmem)
         tiles["decode_attention"] = max(512, _fit(skv, 2048, 512))
     tiles["conv2d"] = (8, 128)
+    # depthwise: the whole output image of a 512-lane channel block per
+    # grid step (the input image is in VMEM whole anyway), so the next
+    # image's DMA overlaps all of this one's taps
+    tiles["depthwise_conv2d"] = (128, 512)
     tiles["wkv_chunk"] = 32
     tiles["ce_chunk"] = flow.ce_chunk
     apply_overrides(tiles, flow)

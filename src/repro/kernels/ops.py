@@ -15,6 +15,7 @@ from repro.kernels import matmul_fused as _mm
 from repro.kernels import attention as _fa
 from repro.kernels import decode_attention as _da
 from repro.kernels import conv2d as _cv
+from repro.kernels import depthwise_conv2d as _dw
 
 
 def matmul_fused(x, w, *, bias=None, w2=None, act=None, tile=None,
@@ -64,3 +65,12 @@ def conv2d_fused(x, w, *, stride=1, padding="SAME", bn=None, act=None,
     return _cv.conv2d_fused(x, w, stride=stride, padding=padding, bn=bn,
                             act=act, block_c=block_c, block_h=block_h,
                             interpret=interpret)
+
+
+def depthwise_conv2d(x, w, *, stride=1, padding="SAME", bn=None, act=None,
+                     tile=None, interpret=False):
+    # the tiling pass hands (block_h, block_c)
+    block_h, block_c = tile if tile is not None else (None, 512)
+    return _dw.depthwise_conv2d(x, w, stride=stride, padding=padding, bn=bn,
+                                act=act, block_h=block_h, block_c=block_c,
+                                interpret=interpret)

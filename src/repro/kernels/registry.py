@@ -289,6 +289,21 @@ def _conv2d_reject(groups=1, **kw) -> Optional[str]:
     return None
 
 
+def _depthwise_reject(x=None, w=None, stride=1, padding="SAME",
+                      **kw) -> Optional[str]:
+    from repro.kernels.depthwise_conv2d import VMEM_CAP, working_set
+    if x is None or w is None:
+        return "depthwise operands not provided to the dispatch predicate"
+    if x.ndim != 4 or w.ndim != 4 or w.shape[2:] != (1, x.shape[-1]):
+        return (f"weight {tuple(w.shape)} is not the (kh, kw, 1, C) filter "
+                f"of a channel-multiplier-1 depthwise conv of {x.shape}")
+    _, h, wd, c = x.shape
+    if working_set(h, wd, w.shape[0], w.shape[1], stride, padding, 1,
+                   min(c, 128), x.dtype.itemsize) > VMEM_CAP:
+        return f"a {h}x{wd} image does not fit VMEM whole"
+    return None
+
+
 def _matmul_workingset(tile, cfg) -> int:
     # x(bm,bk) + w(bk,bn) in bf16 + fp32 accumulator + bf16 out tile —
     # the same model select_matmul_tile sizes against (passes/tiling.py)
@@ -377,6 +392,10 @@ def _register_builtin():
             static_reject=lambda attrs, cfg:
                 _conv2d_reject(groups=attrs.get("groups", 1)),
             tile_candidates=_conv2d_tile_candidates))
+    REGISTRY.register(
+        "depthwise_conv2d", "pallas", kops.depthwise_conv2d,
+        rejects=_depthwise_reject,
+        contract=KernelContract(tile_key="depthwise_conv2d"))
     REGISTRY.register("rg_lru", "pallas", lru_scan)
 
 

@@ -21,8 +21,8 @@ def _conv(b: Block, name: str, cin: int, cout: int, k: int, stride: int = 1,
 
 def _dwconv(b: Block, name: str, c: int, k: int, stride: int = 1) -> None:
     b.add("h", "depthwise_conv2d", "h",
-          params=[P(f"{name}_w", (k, k, c, 1),
-                    ("conv_k", "conv_k", "channels", "none"))],
+          params=[P(f"{name}_w", (k, k, 1, c),
+                    ("conv_k", "conv_k", "none", "channels"))],
           stride=stride, padding="SAME")
 
 
@@ -68,7 +68,11 @@ def build_lenet5(cfg: ModelConfig) -> Graph:
     return Graph(cfg.name, blocks, meta={"config": cfg})
 
 
-_MOBILENET_PLAN = [  # (cout, stride) for each depthwise-separable block
+# (cout, stride) of each depthwise-separable block, Table 1 of the
+# MobileNets paper, with one departure: Table 1 gives the last depthwise
+# conv stride 2 at 7x7x1024, which would leave a 4x4 map before the 7x7
+# average pool; it has stride 1 here, as in every public implementation
+_MOBILENET_PLAN = [
     (64, 1), (128, 2), (128, 1), (256, 2), (256, 1), (512, 2),
     (512, 1), (512, 1), (512, 1), (512, 1), (512, 1), (1024, 2), (1024, 1),
 ]

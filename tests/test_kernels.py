@@ -300,3 +300,107 @@ def test_conv2d_fold_counter():
         cm.param_shapes(), jax.ShapeDtypeStruct((1, 224, 224, 3), f32))
     assert out.shape == (1, CONFIG.vocab_size)
     assert (folded.value - f0, dispatched.value - d0) == (1, 18)
+
+
+# depthwise convs at MobileNetV1's channel counts and strides, at reduced
+# H and W, odd and even: (N, H, W, C, stride[, padding, tile]); tile
+# (block_h, block_c) None is the kernel's default (whole image, 512 lanes)
+DW_SPECS = [
+    (2, 12, 12, 32, 1),         # ds0: lane-sparse, stride 1
+    (2, 13, 13, 64, 2),         # ds1: lane-sparse, stride 2, odd side
+    (1, 9, 9, 128, 1),
+    (2, 10, 10, 128, 2),
+    (1, 7, 11, 512, 1, "SAME", (4, 128)),   # 4 channel blocks, 2 row blocks
+    (2, 8, 8, 512, 2),
+    (1, 7, 7, 1024, 1),         # ds12: two 512-lane channel blocks
+    (1, 9, 6, 1024, 2, "SAME", (2, 256)),
+    (1, 11, 11, 64, 2, "VALID"),
+]
+
+
+def _dw_ref(x, w, stride, padding, bn):
+    y = jax.lax.conv_general_dilated(
+        x.astype(jnp.float32), w.astype(jnp.float32), (stride, stride),
+        padding, dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=x.shape[-1],
+        precision=jax.lax.Precision.HIGHEST)
+    scale, bias, mean, var = bn
+    y = (y - mean) * jax.lax.rsqrt(var + 1e-5) * scale + bias
+    return jax.nn.relu(y)
+
+
+def _dw_case(spec, dt):
+    N, H, W, C, s, *rest = spec
+    padding = rest[0] if rest else "SAME"
+    tile = rest[1] if len(rest) > 1 else None
+    x = jnp.asarray(R.randn(N, H, W, C), dt)
+    w = jnp.asarray(R.randn(3, 3, 1, C) / 3, dt)
+    bn = (jnp.asarray(R.uniform(0.75, 1.25, C), jnp.float32),
+          jnp.asarray(0.1 * R.randn(C), jnp.float32),
+          jnp.asarray(0.1 * R.randn(C), jnp.float32),
+          jnp.asarray(R.uniform(0.5, 1.5, C), jnp.float32))
+    y = ops.depthwise_conv2d(x, w, stride=s, padding=padding, bn=bn,
+                             act="relu", tile=tile, interpret=True)
+    r = _dw_ref(x, w, s, padding, bn)
+    assert y.shape == r.shape and y.dtype == dt
+    return y, r
+
+
+@pytest.mark.parametrize("spec", DW_SPECS)
+def test_depthwise_conv2d(spec):
+    # f32 in and out: the kernel's sums differ from lax.conv only in the
+    # order of 9 f32 terms and in scaling the taps by the folded batch norm
+    y, r = _dw_case(spec, jnp.float32)
+    assert relerr(y, r) < 1e-5
+
+
+@pytest.mark.parametrize("spec", DW_SPECS[:2] + DW_SPECS[5:6])
+def test_depthwise_conv2d_bf16(spec):
+    # bf16 in and out, f32 inside: the error is the bf16 rounding of the
+    # output (2**-9 of each value, so at most that of the largest)
+    y, r = _dw_case(spec, jnp.bfloat16)
+    assert relerr(y, r) < 2.0 ** -8
+
+
+def test_depthwise_conv2d_sites():
+    """Every depthwise conv of the MobileNetV1 program (224 px, folded)
+    dispatches to the kernel: 9 call sites for 13 convs, since folding
+    traces the five identical 512-channel blocks once; nothing falls back.
+    ResNet-34's program keeps its 18 conv2d sites and has no depthwise."""
+    from repro import flow
+    from repro.configs import mobilenetv1, resnet34
+    from repro.configs.base import FlowConfig, ShapeConfig
+    from repro.kernels.registry import DISPATCH_REJECTIONS
+    from repro.obs import METRICS
+
+    def sites(cfg):
+        names = ("depthwise_conv2d", "conv2d")
+        c = [METRICS.counter(f"kernels.dispatch.pallas_interpret.{n}")
+             for n in names]
+        before = [k.value for k in c]
+        cm = flow.compile(cfg, ShapeConfig("dw", "prefill", 1, 1),
+                          FlowConfig(mode="folded"),
+                          backend="pallas_interpret")
+        out = jax.eval_shape(
+            lambda p, x: cm.apply(p, {"images": x}, mode="prefill")[0],
+            cm.param_shapes(),
+            jax.ShapeDtypeStruct((1, 224, 224, 3), jnp.float32))
+        assert out.shape == (1, cfg.vocab_size)
+        return tuple(k.value - b for k, b in zip(c, before))
+
+    assert sites(mobilenetv1.CONFIG) == (9, 10)
+    assert sites(resnet34.CONFIG) == (0, 18)
+    assert not [k for k in DISPATCH_REJECTIONS if k[0] == "depthwise_conv2d"]
+
+
+def test_depthwise_conv2d_rejects():
+    """The capability predicate: a channel multiplier other than 1 and an
+    image too large for VMEM fall back to the reference conv."""
+    from repro.kernels.registry import REGISTRY
+    impl = REGISTRY.get("depthwise_conv2d", "pallas")
+    x = jnp.zeros((1, 14, 14, 64), jnp.bfloat16)
+    assert impl.reject_reason(x=x, w=jnp.zeros((3, 3, 1, 64))) is None
+    assert "multiplier" in impl.reject_reason(x=x,
+                                              w=jnp.zeros((3, 3, 1, 128)))
+    big = jax.ShapeDtypeStruct((1, 2048, 2048, 128), jnp.bfloat16)
+    assert "VMEM" in impl.reject_reason(x=big, w=jnp.zeros((3, 3, 1, 128)))

@@ -13,20 +13,21 @@ SMOKE_TRAIN = ShapeConfig("smoke", "train", 16, 2)
 
 BASE_TILES = ("{'matmul': (128, 128, 128), 'attention': (128, 128), "
               "'decode_attention': 512, 'conv2d': (8, 128), "
-              "'wkv_chunk': 16, 'ce_chunk': 256}")
+              "'depthwise_conv2d': (8, 128), 'wkv_chunk': 16, "
+              "'ce_chunk': 256}")
 
 # KernelRegistry resolution on the CPU test platform: "auto" resolves every
 # accelerable op to the reference backend (Pallas is chosen on TPU only)
 KERNELS = ("  kernels: backend=auto attention=ref conv2d=ref "
-           "copy_block=ref decode_attention=ref glu_matmul=ref matmul=ref "
-           "paged_decode_attention=ref rg_lru=ref")
+           "copy_block=ref decode_attention=ref depthwise_conv2d=ref "
+           "glu_matmul=ref matmul=ref paged_decode_attention=ref rg_lru=ref")
 
 GOLDEN = {
     ("lenet5", "opt"): f"""\
 plan[lenet5 x bench] mode=pipelined
   passes: fuse=True fold=True tiles=True cw=True prec=bf16
   units: 3 (0 folded: )
-  tiles: {{'matmul': (64, 120, 84), 'conv2d': (8, 128), 'wkv_chunk': 32, 'ce_chunk': 256}}
+  tiles: {{'matmul': (64, 120, 84), 'conv2d': (8, 128), 'depthwise_conv2d': (128, 512), 'wkv_chunk': 32, 'ce_chunk': 256}}
 {KERNELS}""",
     ("lenet5", "base"): f"""\
 plan[lenet5 x bench] mode=folded
@@ -38,7 +39,7 @@ plan[lenet5 x bench] mode=folded
 plan[mobilenetv1 x bench] mode=pipelined
   passes: fuse=True fold=True tiles=True cw=True prec=bf16
   units: 15 (0 folded: )
-  tiles: {{'matmul': (64, 1024, 512), 'conv2d': (8, 128), 'wkv_chunk': 32, 'ce_chunk': 256}}
+  tiles: {{'matmul': (64, 1024, 512), 'conv2d': (8, 128), 'depthwise_conv2d': (128, 512), 'wkv_chunk': 32, 'ce_chunk': 256}}
 {KERNELS}""",
     ("mobilenetv1", "base"): f"""\
 plan[mobilenetv1 x bench] mode=folded
@@ -50,7 +51,7 @@ plan[mobilenetv1 x bench] mode=folded
 plan[resnet34 x bench] mode=pipelined
   passes: fuse=True fold=True tiles=True cw=True prec=bf16
   units: 18 (0 folded: )
-  tiles: {{'matmul': (64, 512, 512), 'conv2d': (8, 128), 'wkv_chunk': 32, 'ce_chunk': 256}}
+  tiles: {{'matmul': (64, 512, 512), 'conv2d': (8, 128), 'depthwise_conv2d': (128, 512), 'wkv_chunk': 32, 'ce_chunk': 256}}
 {KERNELS}""",
     ("resnet34", "base"): f"""\
 plan[resnet34 x bench] mode=folded
@@ -75,7 +76,7 @@ def test_lm_plan_golden():
 plan[llama3.2-1b x smoke] mode=folded
   passes: fuse=True fold=True tiles=True cw=True prec=bf16
   units: 3 (1 folded: 3x1)
-  tiles: {{'matmul': (16, 64, 192), 'attention': (16, 16), 'decode_attention': 512, 'conv2d': (8, 128), 'wkv_chunk': 32, 'ce_chunk': 256}}
+  tiles: {{'matmul': (16, 64, 192), 'attention': (16, 16), 'decode_attention': 512, 'conv2d': (8, 128), 'depthwise_conv2d': (128, 512), 'wkv_chunk': 32, 'ce_chunk': 256}}
 {KERNELS}"""
 
 
@@ -91,7 +92,7 @@ def test_lm_plan_golden_sharded():
 plan[llama3.2-1b x smoke] mode=folded
   passes: fuse=True fold=True tiles=True cw=True prec=bf16
   units: 3 (1 folded: 3x1)
-  tiles: {{'matmul': (16, 64, 192), 'attention': (16, 16), 'decode_attention': 512, 'conv2d': (8, 128), 'wkv_chunk': 32, 'ce_chunk': 256}}
+  tiles: {{'matmul': (16, 64, 192), 'attention': (16, 16), 'decode_attention': 512, 'conv2d': (8, 128), 'depthwise_conv2d': (128, 512), 'wkv_chunk': 32, 'ce_chunk': 256}}
   sharding: mesh={{data:2,model:2}} dp=data:2 tp=model:2 pp=- params[tp=7 fsdp=4 repl=0]
 {KERNELS}"""
 

@@ -1,7 +1,7 @@
 """Compile rehearsal: the main path's Pallas kernels compiled for a
 described TPU v5e chip at real widths (llama3.2-1b: H=32, KV=8, D=64,
-block 16; ResNet-34 at 224 px, the MobileNetV1 stem and LeNet-5's conv1,
-batch 8; bf16).
+block 16; ResNet-34 at 224 px, the MobileNetV1 stem and depthwise convs
+and LeNet-5's conv1, batch 8; bf16).
 
 Nothing runs: each test lowers one kernel through the TPU compiler
 (Mosaic) with ``interpret=False`` and checks that a TPU custom call came
@@ -113,3 +113,20 @@ def test_conv2d_resnet34(one_chip, hw, ci, co, k, stride):
                                  act="relu", tile=(8, 128))
     _compile(one_chip, conv, ((B, hw, hw, ci), BF), ((k, k, ci, co), BF),
              ((co,), f32), ((co,), f32), ((co,), f32), ((co,), f32))
+
+
+@pytest.mark.parametrize("hw,c,stride", [
+    (112, 32, 1), (112, 64, 2), (56, 128, 1), (56, 128, 2), (28, 256, 1),
+    (28, 256, 2), (14, 512, 1), (14, 512, 2), (7, 1024, 1),
+])
+def test_depthwise_conv2d_mobilenetv1(one_chip, hw, c, stride):
+    """MobileNetV1's nine distinct depthwise convs at 224 px, with the
+    tiling pass's tile: lane-sparse 32 and 64 channels, stride-2 phases
+    staged through 128-lane f32 rows, 7 and 14 output columns."""
+    f32 = jnp.float32
+
+    def conv(x, w, *bn):
+        return kops.depthwise_conv2d(x, w, stride=stride, bn=bn, act="relu",
+                                     tile=(128, 512))
+    _compile(one_chip, conv, ((B, hw, hw, c), BF), ((3, 3, 1, c), BF),
+             ((c,), f32), ((c,), f32), ((c,), f32), ((c,), f32))
